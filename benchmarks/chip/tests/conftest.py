@@ -1,0 +1,9 @@
+"""Puts the checkout root and ``src`` on the path for the benchmark's
+own tests."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[3]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
