@@ -20,9 +20,9 @@ import sys
 import time
 
 from benchmarks import (compress_bench, dist_svd_bench, fig1_random,
-                        incremental_bench, roofline, schedule_bench,
-                        serve_bench, sparse_bench, stream_bench,
-                        table1_images, table1_words, tol_bench)
+                        incremental_bench, schedule_bench, serve_bench,
+                        sparse_bench, stream_bench, table1_images,
+                        table1_words, tol_bench)
 from benchmarks.common import use_compile_cache
 
 SECTIONS = {
@@ -32,7 +32,6 @@ SECTIONS = {
     "compress": compress_bench.main,
     "dist_svd": dist_svd_bench.main,
     "incremental": incremental_bench.main,
-    "roofline": roofline.main,
     "schedule": schedule_bench.main,
     "serve": serve_bench.main,
     "sparse": sparse_bench.main,

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 
+from repro import obs
 from repro.core import contact
 from repro.core.distributed import (dist_col_mean, dist_srsvd,
                                     dist_srsvd_streamed,
@@ -112,6 +113,7 @@ def _dist_dense_min_size() -> int:
     return DIST_DENSE_MIN_SIZE if v is None else int(v)
 
 
+@obs.spanned("repro.factorize")
 def factorize(x_or_op, k: int | None = None, *, K: int | None = None,
               q: int = 0, tol: float | None = None, b: int = 8,
               max_K: int | None = None, mu=None, center: bool = False,
@@ -202,7 +204,8 @@ def factorize(x_or_op, k: int | None = None, *, K: int | None = None,
     if mesh is not None:
         if isinstance(x_or_op, RowShardedBlockedOp):
             if center and mu is None:
-                mu = x_or_op.col_mean()
+                with obs.span("repro.center"):
+                    mu = x_or_op.col_mean()
             if tol is not None:
                 return dist_srsvd_tol_streamed(
                     x_or_op, mu, tol, b=b, max_K=max_K, mesh=mesh,
@@ -214,7 +217,8 @@ def factorize(x_or_op, k: int | None = None, *, K: int | None = None,
                 warm_start=warm_start, engine=engine)
         if isinstance(x_or_op, ShardedBlockedOp):
             if center and mu is None:
-                mu = x_or_op.col_mean()
+                with obs.span("repro.center"):
+                    mu = x_or_op.col_mean()
             if tol is not None:
                 return dist_srsvd_tol_streamed(
                     x_or_op, mu, tol, b=b, max_K=max_K, mesh=mesh,
@@ -240,7 +244,8 @@ def factorize(x_or_op, k: int | None = None, *, K: int | None = None,
         if tol is None and int(onp.prod(jnp.shape(x_or_op))) \
                 >= _dist_dense_min_size():
             if center and mu is None:
-                mu = dist_col_mean(x_or_op, mesh, row_axis, col_axis)
+                with obs.span("repro.center"):
+                    mu = dist_col_mean(x_or_op, mesh, row_axis, col_axis)
             # Forced-cold case (DESIGN.md §17): the resident-shard
             # collective draws its sketch inside the shard_map, so the
             # warm start is dropped and the solve runs cold.
@@ -250,7 +255,8 @@ def factorize(x_or_op, k: int | None = None, *, K: int | None = None,
     op = as_linop(x_or_op)
     eng = engine if engine is not None else contact.get_engine()
     if center and mu is None:
-        mu = eng.col_mean(op)
+        with obs.span("repro.center"):
+            mu = eng.col_mean(op)
     if tol is not None:
         return srsvd_tol(op, mu, tol=tol, b=b, q=q, key=key,
                          max_K=max_K, shift=sched, engine=eng)

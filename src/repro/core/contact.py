@@ -38,6 +38,8 @@ from collections.abc import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 # --------------------------------------------------------------------------
 # The rank-1 shift algebra.  THE single implementation: every shift
 # correction in the codebase is one of these four functions.
@@ -308,12 +310,15 @@ class ContactEngine:
         return G if mu is None else rank1_correct(G, mu, s)
 
     # -- operator-level contact points ---------------------------------
+    @obs.spanned("repro.contact", kind="matmat")
     def matmat(self, op, B):
         return op.matmat(B)
 
+    @obs.spanned("repro.contact", kind="rmatmat")
     def rmatmat(self, op, B):
         return op.rmatmat(B)
 
+    @obs.spanned("repro.contact", kind="shifted_matmat")
     def shifted_matmat(self, op, B, mu):
         """(X - mu 1^T) @ B through ``op``; plain ``X @ B`` when mu is None.
 
@@ -334,6 +339,7 @@ class ContactEngine:
             return self.sparse_shifted_matmat(source, B, mu)
         return rank1_correct(op.matmat(B), *shift_vectors_matmat(B, mu))
 
+    @obs.spanned("repro.contact", kind="shifted_rmatmat")
     def shifted_rmatmat(self, op, B, mu):
         """(X - mu 1^T)^T @ B through ``op``; ``X^T @ B`` when mu is None."""
         if mu is None:
@@ -348,6 +354,7 @@ class ContactEngine:
         u, w = shift_vectors_rmatmat(B, mu, op.shape[1], op.dtype)
         return rank1_correct(op.rmatmat(B), u, w)
 
+    @obs.spanned("repro.contact", kind="shifted_gram_matmat")
     def shifted_gram_matmat(self, op, B, mu):
         """(X - mu 1^T)(X - mu 1^T)^T @ B — the power-iteration Gram
         contact, composed from the two existing contact points (so every
@@ -367,6 +374,7 @@ class ContactEngine:
             return G if mu is None else rank1_correct(G, mu, s)
         return self.shifted_matmat(op, self.shifted_rmatmat(op, B, mu), mu)
 
+    @obs.spanned("repro.contact", kind="project_residual")
     def project_residual(self, op, Q, B, mu):
         """``(I - Q Q^T)(X - mu 1^T) @ B`` — the adaptive range finder's
         growth contact (DESIGN.md §16): sample the *residual* of the
@@ -631,9 +639,11 @@ class ContactEngine:
             Y = jnp.concatenate(Y_parts, axis=0)
         return Y, Z_acc
 
+    @obs.spanned("repro.contact", kind="col_mean")
     def col_mean(self, op):
         return op.col_mean()
 
+    @obs.spanned("repro.contact", kind="fro_norm2")
     def fro_norm2(self, op):
         return op.fro_norm2()
 

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as onp
 
+from repro import obs
 from repro.core import contact, stopping as _stopping
 from repro.core.qr_update import qr_rank1_update
 
@@ -187,18 +188,21 @@ class FixedRangeFinder(RangeFinder):
         dt = work_dtype(op)
         K = self.K
 
-        omega = self._draw(key, n, K, dt)                       # line 2
-        X1 = eng.matmat(op, omega)                              # line 3
-        Q1, R1 = _qr(X1)                                        # line 4
+        with obs.span("repro.sample"):
+            omega = self._draw(key, n, K, dt)                   # line 2
+            X1 = eng.matmat(op, omega)                          # line 3
+        with obs.span("repro.qr"):
+            Q1, R1 = _qr(X1)                                    # line 4
 
         if mu is not None:                                      # lines 5-7
-            v = (omega.sum(axis=0) if self.shift_mode == "exact"
-                 else jnp.ones(K, dt))
-            if self.use_qr_update:
-                Q, _ = qr_rank1_update(Q1, R1, -mu, v)          # line 6
-            else:
-                Q, _ = _qr(contact.rank1_correct(
-                    contact.basis_matmul(Q1, R1), mu, v))
+            with obs.span("repro.shift_update"):
+                v = (omega.sum(axis=0) if self.shift_mode == "exact"
+                     else jnp.ones(K, dt))
+                if self.use_qr_update:
+                    Q, _ = qr_rank1_update(Q1, R1, -mu, v)      # line 6
+                else:
+                    Q, _ = _qr(contact.rank1_correct(
+                        contact.basis_matmul(Q1, R1), mu, v))
         else:
             Q = Q1
 

@@ -29,6 +29,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import (contact, rangefinder as _rangefinder,
                         schedule as _schedule, stopping as _stopping)
 from repro.core.linop import as_linop
@@ -157,14 +158,16 @@ def srsvd(X, mu, k: int, K: int | None = None, q: int = 0, *,
 
     # Phase 2 — shift-corrected post-process.
     # line 12 / Eq. 10:  Y = Q^T X - (Q^T mu) 1^T  ==  ((Xbar)^T Q)^T.
-    Y = eng.shifted_rmatmat(op, Q, mu).T                    # (K, n)
+    with obs.span("repro.project"):
+        Y = eng.shifted_rmatmat(op, Q, mu).T                # (K, n)
 
-    U1, S, Vt = jnp.linalg.svd(Y, full_matrices=False)      # line 13
-    U = contact.basis_matmul(Q, U1)                         # line 14
-    res = SVDResult(U[:, :k], S[:k], Vt[:k, :])
+    with obs.span("repro.small_svd"):
+        U1, S, Vt = jnp.linalg.svd(Y, full_matrices=False)  # line 13
+        U = contact.basis_matmul(Q, U1)                     # line 14
+        res = SVDResult(U[:, :k], S[:k], Vt[:k, :])
     if rule is None:
         return res
-    return res, _stopping.build_report(rule, growth.tstate, S[:k], m,
+    return res, _stopping.build_report(rule, growth.tstate, res.S, m,
                                        growth.qmax, growth.fro2,
                                        k_found=growth.k_found)
 
@@ -310,23 +313,23 @@ def srsvd_batched(Xs, mus, k: int, K: int | None = None, q: int = 0, *,
                             keys)
 
 
-#: times _jit_svd_batched actually traced (one per distinct static
-#: signature + stacked shape) — the server's coalescing tests and its
-#: observability counters read the delta around each batched call to
-#: prove that same-shape requests share one compilation.
-_BATCHED_TRACES = [0]
+#: The ``obs`` event counted each time _jit_svd_batched traces (one per
+#: distinct static signature + stacked shape) — the server's coalescing
+#: tests and its observability counters read the delta around each
+#: batched call to prove that same-shape requests share one compilation.
+_BATCHED_TRACED = "batched_traced"
 
 
 def batched_trace_count() -> int:
     """Cumulative trace count of the batched solver (monotone)."""
-    return _BATCHED_TRACES[0]
+    return obs.total(_BATCHED_TRACED)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "K", "q", "shifted", "shift",
                                     "stop"))
 def _jit_svd_batched(Xs, mus, k, K, q, shifted, shift, stop, keys):
-    _BATCHED_TRACES[0] += 1          # trace-time side effect, by design
+    obs.count(_BATCHED_TRACED)        # trace-time side effect, by design
 
     def one(X, mu, key):
         return srsvd(X, mu if shifted else None, k, K, q, key=key,

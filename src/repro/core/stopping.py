@@ -79,6 +79,8 @@ import jax.numpy as jnp
 import numpy as onp
 from jax import lax
 
+from repro import obs
+
 
 def sigma_estimates(R: jax.Array, alpha=None) -> jax.Array:
     """Descending singular-value estimates from an iteration's R factor.
@@ -450,7 +452,8 @@ def resolve_fro2(rule: StopRule | None, eng, op, mu):
     if rule is None or not rule.needs_fro2:
         return None
     try:
-        return eng.xbar_fro_norm2(op, mu)
+        with obs.span("repro.norm"):
+            return eng.xbar_fro_norm2(op, mu)
     except NotImplementedError as e:
         raise ValueError(
             f"{type(rule).__name__} needs ||Xbar||_F^2 but "
@@ -508,15 +511,16 @@ def build_report(rule: StopRule, state: StopState, S, m: int,
     monitored components whose final PVE sits inside the rule's
     ``k_eff_band`` — 0 when no power iteration ran (the init PVE is
     inf), since nothing was iterated to convergence."""
-    post = None if fro2 is None else posterior_rel_err(
-        S, fro2, m, K=state.prev_s.shape[0])
-    k_eff = jnp.sum(
-        state.mask & (state.pve <= rule.k_eff_band)).astype(jnp.int32)
-    return ConvergenceReport(
-        iters_run=state.t, pve_trace=state.trace,
-        sigma_estimates=state.prev_s, posterior_rel_err=post,
-        xbar_fro2=None if fro2 is None else jnp.asarray(fro2),
-        qmax=qmax, k_eff=k_eff, k_found=k_found)
+    with obs.span("repro.report"):
+        post = None if fro2 is None else posterior_rel_err(
+            S, fro2, m, K=state.prev_s.shape[0])
+        k_eff = jnp.sum(
+            state.mask & (state.pve <= rule.k_eff_band)).astype(jnp.int32)
+        return ConvergenceReport(
+            iters_run=state.t, pve_trace=state.trace,
+            sigma_estimates=state.prev_s, posterior_rel_err=post,
+            xbar_fro2=None if fro2 is None else jnp.asarray(fro2),
+            qmax=qmax, k_eff=k_eff, k_found=k_found)
 
 
 def run_power_loop(sched, rule: StopRule | None, eng, op, Q, mu,
@@ -550,7 +554,8 @@ def run_power_loop(sched, rule: StopRule | None, eng, op, Q, mu,
         for t in range(qmax):
             if early and concrete_done(tstate):
                 break
-            Q, sstate, tstate = step(t, Q, sstate, tstate)
+            with obs.span("repro.power_step", t=t):
+                Q, sstate, tstate = step(t, Q, sstate, tstate)
         return Q, sstate, tstate
     if loop == "fori":
         if early:

@@ -110,6 +110,7 @@ def matmul_rank1(A: jax.Array, B: jax.Array, u: jax.Array, w: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="matmul_rank1",
     )(A_p, B_p, u_p, w_p)
     return out[:m, :K]
 
