@@ -18,7 +18,12 @@ TPU adaptation note: the classical formulation is a sequence of scalar
 Givens rotations.  We keep the rotation *sequence* (it is inherently
 sequential along K) but each rotation is applied to whole rows/columns as
 vector ops (VPU-friendly), driven by ``lax.fori_loop``.  K is small
-(K = 2k <= a few hundred) so this is never a bottleneck; see DESIGN.md §3.
+(K = 2k <= a few hundred), so the sweeps' arithmetic is cheap; see
+DESIGN.md §3.  ``qr_rank1_update`` is a module-level ``jax.jit``: the
+loop bodies are closures made fresh on each call, so an eager call
+traces and lowers both sweeps again every time, with the device idle
+meanwhile.  Jitted, it compiles once per shape and dtype of its
+arguments; inside another ``jit`` it is inlined.
 
 Known edge (DESIGN.md §16, pinned by ``tests/test_qr_update.py``): when
 R is *exactly* singular — zero pivots from a base factored past its
@@ -69,6 +74,7 @@ def _rot_cols(M, i, c, s):
         M, jnp.stack([hi, lo], axis=1), i, axis=1)
 
 
+@jax.jit
 def qr_rank1_update(Q: jax.Array, R: jax.Array, u: jax.Array, v: jax.Array
                     ) -> tuple[jax.Array, jax.Array]:
     """Thin QR of ``Q @ R + u v^T``.

@@ -68,6 +68,19 @@ def test_factorize_writes_its_phases_in_order(centred, tmp_path):
     assert len(_leaves(inner)) == 9
 
 
+def test_a_repeat_factorize_lowers_nothing(centred):
+    """After the first call, a call with a new key lowers no program
+    under any span: the Givens shift update is compiled once a shape."""
+    jax.block_until_ready(api.factorize(centred, 10, K=20, q=2, center=True,
+                                        key=jax.random.PRNGKey(1)))
+    before = obs.counts()
+    jax.block_until_ready(api.factorize(centred, 10, K=20, q=2, center=True,
+                                        key=jax.random.PRNGKey(2)))
+    lowered = {k: v - before.get(k, 0) for k, v in obs.counts().items()
+               if k.endswith("/lowered") and v != before.get(k, 0)}
+    assert lowered == {}
+
+
 @pytest.mark.parametrize("blocked, spans, passes", [(False, 3, 2),
                                                      (True, 1, 1)])
 def test_a_gram_contact_holds_one_leaf_a_pass(centred, tmp_path, blocked,

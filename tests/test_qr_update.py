@@ -4,7 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import qr_rank1_update
+from repro.core.ref import qr_rank1_update_ref
 
 
 @pytest.mark.parametrize("m,K", [(16, 4), (64, 16), (200, 32), (33, 7)])
@@ -63,6 +65,51 @@ def test_qr_update_jit_compatible(rng):
                     jnp.asarray(v))
     np.testing.assert_allclose(np.asarray(Q2) @ np.asarray(R2),
                                A + np.outer(u, v), atol=2e-5)
+
+
+def _factors(rng, m, K):
+    """(Q, R, u, v) on the device, ready, so that making them is not
+    counted with the update's own lowerings."""
+    A = rng.standard_normal((m, K)).astype(np.float32)
+    Q, R = np.linalg.qr(A)
+    u = rng.standard_normal(m).astype(np.float32)
+    v = rng.standard_normal(K).astype(np.float32)
+    return [jax.block_until_ready(jnp.asarray(x)) for x in (Q, R, u, v)]
+
+
+def _lowerings(*args):
+    """(lowerings counted while the update runs, its result)."""
+    before = obs.total("lowered")
+    out = jax.block_until_ready(qr_rank1_update(*args))
+    return obs.total("lowered") - before, out
+
+
+def test_qr_update_repeat_call_lowers_nothing(rng):
+    """A second call at the same shapes, with new values, reuses the
+    compiled update, and still matches the re-factorization oracle."""
+    m, K = 64, 16
+    first = _factors(rng, m, K)
+    _lowerings(*first)
+    Q, R, u, v = _factors(rng, m, K)
+    lowered, (Q2, R2) = _lowerings(Q, R, u, v)
+    assert lowered == 0
+    Q, R, u, v = (np.asarray(x) for x in (Q, R, u, v))
+    Qr, Rr = qr_rank1_update_ref(Q, R, u, v)
+    Q2, R2 = np.asarray(Q2), np.asarray(R2)
+    np.testing.assert_allclose(Q2 @ R2, Qr @ Rr, atol=2e-5)
+    np.testing.assert_allclose(Q2 @ Q2.T, Qr @ Qr.T, atol=2e-5)
+    np.testing.assert_allclose(np.abs(np.diag(R2)), np.abs(np.diag(Rr)),
+                               atol=2e-5)
+    np.testing.assert_allclose(Q2.T @ Q2, np.eye(K), atol=2e-5)
+
+
+def test_qr_update_lowers_once_per_new_K(rng):
+    """The update compiles once per shape: from a cleared cache the
+    first call at K lowers once, a repeat none, a new K once more."""
+    qr_rank1_update.clear_cache()
+    m = 48
+    counts = [_lowerings(*_factors(rng, m, K))[0] for K in (10, 10, 11)]
+    assert counts == [1, 0, 1]
 
 
 # ---------------------------------------------------------------------
